@@ -29,6 +29,7 @@ from benchmarks import (bench_async_overlap, bench_e2e_paged,
                         bench_sharded, bench_static_analysis,
                         bench_table1, bench_vm_throughput, bench_wcet)
 from benchmarks._workbench import fmt_table
+from repro.launch.compile_cache import enable_compile_cache
 
 # Per-module wall-clock budget: one hung bench (an XLA compile gone
 # quadratic, a deadlocked wait) must report as a module failure instead
@@ -110,6 +111,7 @@ def main() -> None:
                     help="CI smoke configuration: small batches, few "
                          "reps, for modules that support it")
     args = ap.parse_args()
+    enable_compile_cache()
 
     all_rows = []
     tables = []

@@ -16,6 +16,7 @@ from repro.core import costmodel as cm
 from repro.core import simulator as sim
 from repro.core.endpoint import TiaraEndpoint
 from repro.core import operators as ops
+from repro.launch.compile_cache import enable_compile_cache
 
 N_NODES = 4
 BLOCK_BYTES = 8192
@@ -24,6 +25,7 @@ REQ_BLOCKS = 160          # the paper's LLaMA3-70B request: 160 blocks
 
 
 def main() -> None:
+    enable_compile_cache()
     k = ops.PagedKVFetch(n_blocks_pool=BLOCKS_PER_NODE,
                          block_bytes=BLOCK_BYTES,
                          max_req_blocks=REQ_BLOCKS)

@@ -10,7 +10,8 @@ and the dispatch table; ``connect()`` gives each tenant a ``Session``
 ``doorbell()`` drains every session's posts as a single batched wave.
 """
 
-from repro import jaxcompat
+import jax
+
 from repro.core import costmodel as cm
 from repro.core import faults
 from repro.core import simulator as sim
@@ -18,9 +19,11 @@ from repro.core import operators as ops
 from repro.core import serving_loop as serving
 from repro.core.endpoint import EndpointError, TiaraEndpoint
 from repro.core.frontend import compile_source
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     # A disaggregated memory node: a graph region and a reply region.
     w = ops.GraphWalk(n_nodes=4096, max_depth=64)
 
@@ -29,7 +32,7 @@ def main() -> None:
     #    more than one device row the pool can later shard over a mesh
     #    (step 6); under XLA_FLAGS=--xla_force_host_platform_device_
     #    count=8 the mesh is 4 real host devices wide.
-    n_dev = max(1, min(4, jaxcompat.device_count()))
+    n_dev = max(1, min(4, len(jax.devices())))
     ep, sessions = TiaraEndpoint.for_tenants(
         [("quickstart", w.regions())], n_devices=n_dev)
     sess = sessions["quickstart"]

@@ -10,9 +10,11 @@ import argparse
 from repro.configs import get_config
 from repro.data import DataConfig
 from repro.training import Trainer, TrainerConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=16)

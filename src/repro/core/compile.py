@@ -952,18 +952,22 @@ class _Tracer:
                 # Route the row gather through the Pallas double-
                 # indirection kernel: rows must be W-aligned in the pool
                 # region (true for every translation table the
-                # workloads build).
+                # workloads build).  The kernel moves 32-bit words, so
+                # each 64-bit row travels as 2W int32 halves.
                 from repro.kernels.tiara_gather.kernel import \
                     tiara_gather_kernel
-                pool_view = lax.dynamic_slice(
-                    self.memf, (pool_base,),
-                    (pool_mask + 1,)).reshape(-1, W)
+                pool_view = lax.bitcast_convert_type(
+                    lax.dynamic_slice(self.memf, (pool_base,),
+                                      (pool_mask + 1,)),
+                    jnp.int32).reshape(-1, 2 * W)
                 rows = tiara_gather_kernel(
                     pool_view,
                     ((paddr & pool_mask).reshape(-1) // W).astype(jnp.int32),
                     jnp.arange(B * cap, dtype=jnp.int32),
                     interpret=(self.impl == "kernel_interpret"),
-                ).reshape(B, cap, W).astype(jnp.int64)
+                )
+                rows = lax.bitcast_convert_type(
+                    rows.reshape(B, cap, W, 2), jnp.int64)
             else:
                 rows = gather_rows(paddr)                # (B, cap, W)
             # commit in (iteration, request, word) order = round-robin

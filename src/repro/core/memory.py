@@ -294,6 +294,22 @@ def pool_sharding(mesh, axis: str = "pool"):
     return NamedSharding(mesh, PartitionSpec(axis, None))
 
 
+def make_device_mesh(n_devices: int, axis: str = "pool"):
+    """A 1-D mesh over the first ``n_devices`` local devices.  Raises
+    with an actionable message when the host exposes fewer (on CPU:
+    relaunch under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)."""
+    import jax
+    from jax.sharding import Mesh
+
+    devs = jax.devices()
+    if len(devs) < n_devices:
+        raise RuntimeError(
+            f"sharded execution needs {n_devices} devices but this "
+            f"process sees {len(devs)}; on CPU relaunch with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_devices}")
+    return Mesh(np.asarray(devs[:n_devices]), (axis,))
+
+
 def shard_pool(mem: np.ndarray, mesh=None, axis: str = "pool"):
     """Place a ``(n_devices, pool_words)`` pool on a device mesh with
     :func:`pool_sharding` — shard-aware pool construction for the
@@ -302,9 +318,8 @@ def shard_pool(mem: np.ndarray, mesh=None, axis: str = "pool"):
     (raises when the host exposes fewer)."""
     import jax
 
-    from repro import jaxcompat
     if mesh is None:
-        mesh = jaxcompat.make_device_mesh(int(mem.shape[0]), axis)
+        mesh = make_device_mesh(int(mem.shape[0]), axis)
     return jax.device_put(np.asarray(mem, dtype=np.int64),
                           pool_sharding(mesh, axis))
 

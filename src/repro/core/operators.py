@@ -86,14 +86,13 @@ class GraphWalk:
         """Random ring permutation; returns the node order (offsets/8)."""
         rng = np.random.default_rng(seed)
         order = rng.permutation(self.n_nodes)
-        graph = np.zeros(self.n_nodes * NODE_WORDS, dtype=np.int64)
-        for i in range(self.n_nodes):
-            node, nxt = order[i], order[(i + 1) % self.n_nodes]
-            graph[node * NODE_WORDS + 0] = 10_000 + node
-            graph[node * NODE_WORDS + 1] = nxt * NODE_WORDS
-            graph[node * NODE_WORDS + 2: node * NODE_WORDS + 8] = \
-                rng.integers(0, 1 << 32, size=6)
-        memory.write_region(mem, rt, device, "graph", graph)
+        graph = np.zeros((self.n_nodes, NODE_WORDS), dtype=np.int64)
+        graph[order, 0] = 10_000 + order
+        graph[order, 1] = np.roll(order, -1) * NODE_WORDS
+        # one draw in ring order: the same stream as six words per node
+        graph[order, 2:] = rng.integers(0, 1 << 32,
+                                        size=(self.n_nodes, NODE_WORDS - 2))
+        memory.write_region(mem, rt, device, "graph", graph.reshape(-1))
         return order
 
     def reference(self, order: np.ndarray, start_idx: int, depth: int) -> int:

@@ -39,6 +39,7 @@ import dataclasses
 from typing import (Dict, Iterator, List, Optional, Sequence, Set, Tuple,
                     Union)
 
+import jax
 import numpy as np
 
 from repro.core import access
@@ -589,7 +590,6 @@ class OperatorRegistry:
                 f"placement={placement!r} executes the mixed lockstep "
                 f"engine over the mesh; mode must be 'auto' or 'mixed', "
                 f"not {mode!r}")
-        from repro import jaxcompat
         n_dev = int(mem.shape[0])
         h = vm.homes_array(homes, len(params))
         plan = tcompile.plan_mixed_batch(ids, homes=h, n_devices=n_dev)
@@ -611,7 +611,7 @@ class OperatorRegistry:
                 # mesh with a failed member: the single-chip engines
                 # model failed devices exactly, the mesh would compute
                 # through the dead chip
-                sharded_feasible=(jaxcompat.device_count() >= n_dev
+                sharded_feasible=(len(jax.devices()) >= n_dev
                                   and not failed),
                 mixed_cached=vm.mixed_engine_cached(
                     self.store_ops(), self.regions, n_dev, int(ids.size),

@@ -1163,7 +1163,7 @@ def _build_sharded_engine(codes: Sequence[np.ndarray], fuels: Sequence[int],
     """
     from jax.sharding import PartitionSpec as _P
 
-    from repro import jaxcompat
+    from repro.core.memory import make_device_mesh
 
     code_np, start_np, end_np, fuel_np, max_window = \
         _program_statics(codes, fuels)
@@ -1175,7 +1175,7 @@ def _build_sharded_engine(codes: Sequence[np.ndarray], fuels: Sequence[int],
     Bp = int(batch_per_device)
     N = n_dev * Bp
     depth = isa.LOOP_STACK_DEPTH
-    mesh = jaxcompat.make_device_mesh(n_dev, axis)
+    mesh = make_device_mesh(n_dev, axis)
 
     def device_body(mem, params, homes, failed, op_sel, arrival):
         # per-device shards: mem (1, P); params (1, Bp, w); homes /
@@ -1303,8 +1303,10 @@ def _build_sharded_engine(codes: Sequence[np.ndarray], fuels: Sequence[int],
                         status=status[None], steps=final.steps[None],
                         regs=final.regs[None], fault=final.fault[None])
 
-    sharded = jaxcompat.shard_map(
-        device_body, mesh,
+    # replication checking off: it rejects collectives under lax.cond
+    # even with a replicated predicate — the conflict-fallback shape
+    sharded = jax.shard_map(
+        device_body, mesh=mesh, check_vma=False,
         in_specs=(_P(axis, None), _P(axis, None, None), _P(axis, None),
                   _P(None), _P(axis, None), _P(axis, None)),
         out_specs=VMResult(mem=_P(axis, None), ret=_P(axis, None),

@@ -29,7 +29,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.compile import masked_row_gather
-from repro.jaxcompat import axis_size as _axis_size
 
 NEG = -1e30
 
@@ -138,7 +137,7 @@ def sharded_paged_attention(mesh: Mesh, dp_axes: Tuple[str, ...],
         # axis order so contiguous page ranges land per rank
         rank = 0
         for a in all_axes:
-            rank = rank * _axis_size(a) + lax.axis_index(a)
+            rank = rank * lax.axis_size(a) + lax.axis_index(a)
         pp_local = k_pages.shape[0]
         base = rank * pp_local
         b, qh, hd = q.shape

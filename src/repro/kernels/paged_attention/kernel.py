@@ -16,10 +16,17 @@ Layout:
   lengths      (B,) int32         tokens currently in each sequence
   out          (B, KVH, G, D)
 
-Grid: (B, KVH, maxp), pages innermost; flash-style running softmax in
-VMEM scratch.  Pages past a sequence's length still prefetch (the table
-pads with page 0) but their compute is skipped with ``pl.when`` — the
-standard dummy-fetch idiom for data-dependent grids.
+The kernel views each pool as (P, page, KVH * D) — a free reshape of the
+contiguous pool — so one grid step DMAs a whole page, every KV head at
+once, and slices each head's D lanes out of the page.  A block of one head,
+(1, page, 1, D), would be the natural spelling, but the TPU lowering
+needs the last two block dimensions to be (8k, 128m) or the full array
+dimensions, and one of KVH heads is neither.
+
+Grid: (B, maxp), pages innermost; flash-style running softmax per KV
+head in VMEM scratch.  Pages past a sequence's length still prefetch
+(the table pads with page 0) but their compute is skipped with
+``pl.when`` — the standard dummy-fetch idiom for data-dependent grids.
 """
 
 from __future__ import annotations
@@ -39,9 +46,10 @@ def _paged_attn_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
                        q_ref, k_ref, v_ref,          # VMEM blocks
                        o_ref,                        # VMEM output block
                        m_scr, l_scr, acc_scr,        # VMEM scratch
-                       *, page_size: int, scale: float):
+                       *, page_size: int, n_kv_heads: int, head_dim: int,
+                       scale: float):
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     length = lengths_ref[b]
 
     @pl.when(i == 0)
@@ -52,28 +60,30 @@ def _paged_attn_kernel(lengths_ref, tables_ref,      # scalar prefetch (SMEM)
 
     @pl.when(i * page_size < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (page, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, page)
         pos = i * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_scr[...]                                  # (G, 1)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_cur)
-        alpha = jnp.exp(m_prev - m_cur)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_cur
+        for h in range(n_kv_heads):
+            lanes = pl.ds(h * head_dim, head_dim)
+            q = q_ref[0, h].astype(jnp.float32) * scale           # (G, D)
+            k = k_ref[0, :, lanes].astype(jnp.float32)            # (page, D)
+            v = v_ref[0, :, lanes].astype(jnp.float32)            # (page, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(pos < length, s, NEG_INF)               # (G, page)
+            m_prev = m_scr[h]                                     # (G, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_cur)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_cur
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finalize():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
@@ -92,26 +102,27 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
     if scale is None:
         scale = head_dim ** -0.5
 
-    grid = (batch, kvh, max_pages)
     kernel = functools.partial(_paged_attn_kernel, page_size=page_size,
+                               n_kv_heads=kvh, head_dim=head_dim,
                                scale=float(scale))
+    page_words = kvh * head_dim
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(batch, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, group, head_dim),
-                         lambda b, h, i, ln, bt: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, head_dim),
-                         lambda b, h, i, ln, bt: (bt[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, head_dim),
-                         lambda b, h, i, ln, bt: (bt[b, i], 0, h, 0)),
+            pl.BlockSpec((1, kvh, group, head_dim),
+                         lambda b, i, ln, bt: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, page_words),
+                         lambda b, i, ln, bt: (bt[b, i], 0, 0)),
+            pl.BlockSpec((1, page_size, page_words),
+                         lambda b, i, ln, bt: (bt[b, i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, head_dim),
-                               lambda b, h, i, ln, bt: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, group, head_dim),
+                               lambda b, i, ln, bt: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, head_dim), jnp.float32),
+            pltpu.VMEM((kvh, group, 1), jnp.float32),
+            pltpu.VMEM((kvh, group, 1), jnp.float32),
+            pltpu.VMEM((kvh, group, head_dim), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -119,5 +130,6 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), q,
+      k_pages.reshape(n_pages, page_size, page_words),
+      v_pages.reshape(n_pages, page_size, page_words))

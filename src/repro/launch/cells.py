@@ -27,7 +27,6 @@ from repro.training.optimizer import AdamWConfig, AdamWState, warmup_cosine
 from repro.training.train_step import TrainState, make_train_step
 
 
-from repro.jaxcompat import mesh_context
 
 
 @dataclasses.dataclass
@@ -256,5 +255,5 @@ def lower_cell(cell: Cell, mesh):
         else None
     jitted = jax.jit(cell.fn, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=cell.donate)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return jitted.lower(*cell.args)

@@ -11,9 +11,11 @@ import jax
 from repro.configs import get_config, reduce_config
 from repro.models import transformer as tf
 from repro.serving import ServingEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-lm")
     ap.add_argument("--requests", type=int, default=6)

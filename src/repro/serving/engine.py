@@ -38,7 +38,7 @@ from repro.configs import ArchConfig
 from repro.core import isa
 from repro.core.endpoint import Completion, EndpointError
 from repro.models import transformer as tf
-from repro.serving.allocator import BlockAllocator
+from repro.serving.allocator import BlockAllocator, OutOfPages
 from repro.serving.resolver import TiaraResolver, expert_layout
 from repro.serving.sampler import sample_tokens
 
@@ -292,7 +292,7 @@ class ServingEngine:
                 need = self.pages_per_seq
                 try:
                     pages = self.allocator.alloc(need, seq.sid)
-                except Exception:
+                except OutOfPages:
                     self.waiting.insert(0, seq)
                     return
                 seq.slot, seq.pages = slot, pages
@@ -466,6 +466,27 @@ class ServingEngine:
         path) — exposed for benches/tests that instrument the fabric
         (``resolver.on_wave``) or drive faults."""
         return self._resolver
+
+    def compiled_hlo(self, mode: str, *, bucket: int = 0) -> str:
+        """Optimized HLO of the engine's jitted ``"prefill"`` (for a
+        prompt bucket of ``bucket`` tokens) or ``"decode"`` program, as
+        compiled for this engine's shapes — a cache hit once the program
+        has run.  For audits of what the device executes."""
+        if mode == "decode":
+            fn, tokens = self._decode_jit, (self.max_slots, 1)
+            tables, lengths = self.block_tables, self.lengths
+        elif mode == "prefill":
+            fn, tokens = self._prefill_jit, (1, bucket)
+            tables = self.block_tables[:1]
+            lengths = self.lengths[:1]
+        else:
+            raise ValueError(f"mode must be 'prefill' or 'decode', "
+                             f"not {mode!r}")
+        batch = {"tokens": jnp.zeros(tokens, jnp.int32),
+                 "caches": self.caches,
+                 "block_tables": jnp.asarray(tables),
+                 "lengths": jnp.asarray(lengths)}
+        return fn.lower(self.params, batch).compile().as_text()
 
     def resolver_audit(self) -> Dict[str, float]:
         """The tiara resolver's rehome/traffic audit (empty dict on the

@@ -76,8 +76,7 @@ def test_int8_psum_accuracy():
         comp = make_grad_compressor(mesh, "pod")
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)}
-        from repro.jaxcompat import mesh_context
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             out = jax.jit(comp)(g)
         # all pods contributed the same replicated grad: psum == 2 * g
         rel = float(jnp.abs(out["w"] - 2 * g["w"]).max()
@@ -188,8 +187,7 @@ def test_sharded_paged_decode_matches_baseline():
             cs = jax.device_put(caches, to_sh(cell.in_specs[1]))
             bs = {k: jax.device_put(v, to_sh(cell.in_specs[2][k]))
                   for k, v in batch.items()}
-            from repro.jaxcompat import mesh_context
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 logits, _ = jax.jit(cell.fn,
                                     in_shardings=to_sh(cell.in_specs),
                                     out_shardings=to_sh(cell.out_specs)

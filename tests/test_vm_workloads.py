@@ -19,6 +19,35 @@ def run_both(vop, rt, mem, params, home=0, failed=None):
     return r2
 
 
+def _graph_populate_loop(n_nodes, seed):
+    """The per-node loop ``GraphWalk.populate`` replaced: the reference
+    its vectorized form must reproduce word for word."""
+    rng = np.random.default_rng(seed)
+    order = rng.permutation(n_nodes)
+    graph = np.zeros(n_nodes * ops.NODE_WORDS, dtype=np.int64)
+    for i in range(n_nodes):
+        node, nxt = order[i], order[(i + 1) % n_nodes]
+        base = node * ops.NODE_WORDS
+        graph[base] = 10_000 + node
+        graph[base + 1] = nxt * ops.NODE_WORDS
+        graph[base + 2: base + ops.NODE_WORDS] = \
+            rng.integers(0, 1 << 32, size=ops.NODE_WORDS - 2)
+    return order, graph
+
+
+@pytest.mark.parametrize("n_nodes,seed", [(1, 0), (2, 5), (64, 0),
+                                          (1000, 3)])
+def test_graph_populate_matches_loop(n_nodes, seed):
+    w = ops.GraphWalk(n_nodes=n_nodes)
+    rt = w.regions()
+    mem = memory.make_pool(1, rt)
+    order = w.populate(mem, rt, seed=seed)
+    ref_order, ref_graph = _graph_populate_loop(n_nodes, seed)
+    assert np.array_equal(order, ref_order)
+    got = memory.read_region(mem, rt, 0, "graph", count=ref_graph.size)
+    assert np.array_equal(got, ref_graph)
+
+
 def test_graph_walk_depths():
     w = ops.GraphWalk(n_nodes=128, max_depth=32)
     rt = w.regions()
