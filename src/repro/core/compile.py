@@ -3,7 +3,7 @@
 The facts the static verifier already proves about an operator (jumps are
 forward-only, every loop has a static trip-count cap, nesting is bounded)
 are exactly what a trace compiler needs: the whole program can be lowered
-at registration time to **straight-line predicated dataflow** — a chain of
+at registration time to **predicated dataflow** — a chain of
 ``jnp.take``-style gathers and deterministic scatters with no interpreter
 dispatch at all.  This is the software analogue of the paper's point that
 hot remote-memory paths (pointer chase, page-table walk, KV block fetch)
@@ -12,8 +12,19 @@ interpreted programs.
 
 Lowering rules (B = request batch, every value is an int64 ``(B,)`` lane):
 
-  * loops unroll to their static cap; iteration ``j`` runs under the
-    predicate ``j < min(trip_reg, cap)``;
+  * a loop lowers to a ``lax.while_loop`` whose body is the predicated
+    trace of one iteration; iteration ``j`` runs under the predicate
+    ``j < min(trip_reg, cap)``, and the loop stops at the static cap or
+    at the first trip with no live lane (such a trip would change
+    nothing).  Each trip's registers and halt mask are materialized in
+    the loop carry: fully unrolled, XLA fuses a pointer chase's hops
+    into one kernel, and because the protection path makes a hop's
+    address and its halt mask depend on each other, XLA:CPU's fusion
+    emitter re-derives every earlier hop once per use — run time
+    exponential in the trip count.  A loop body is traced once, so the
+    trace grows with the program's length (at most the 1024-entry
+    instruction store), not with its step bound: every verified operator
+    compiles;
   * forward jumps become predicate splits: the fall-through side continues
     under ``pred & ~take`` and the taken lanes re-join at the target pc
     (a jump that escapes loop bodies masks the remaining iterations —
@@ -45,6 +56,7 @@ the simulator's job), so the compiled path drops it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import jax
@@ -69,7 +81,9 @@ _REG_MASK = isa.NUM_REGS - 1
 _DEV_HOME = -1
 _DEV_LATCHED = -2
 
-DEFAULT_UNROLL_LIMIT = 4096
+# the _Tracer state a loop trip carries besides the registers
+_CARRIED = ("memf", "halted", "ret", "status", "steps",
+            "f_site", "f_addr", "f_dev", "f_aux")
 
 # Iterations per double-buffered gather-chain chunk: small enough that
 # chunk k+1's row gather overlaps a meaningful fraction of chunk k's
@@ -83,25 +97,6 @@ DBUF_CHUNK = _costmodel.EngineCost().dbuf_chunk_iters
 class CompileError(Exception):
     pass
 
-
-def why_not_compilable(op: VerifiedOperator,
-                       unroll_limit: int = DEFAULT_UNROLL_LIMIT
-                       ) -> Optional[str]:
-    """None if the operator can be trace-compiled, else a reason string.
-
-    The verifier already guarantees loop-freeness or bounded unrollability;
-    the only extra constraint is that the fully unrolled trace stays small
-    enough to be worth baking into one XLA program.
-    """
-    if op.step_bound > unroll_limit:
-        return (f"worst-case trace of {op.step_bound} instructions exceeds "
-                f"the unroll limit of {unroll_limit}")
-    return None
-
-
-def compilable(op: VerifiedOperator,
-               unroll_limit: int = DEFAULT_UNROLL_LIMIT) -> bool:
-    return why_not_compilable(op, unroll_limit) is None
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +198,7 @@ class Segment:
 class MixedPlan:
     """The compiled path's plan for a mixed-op batch.
 
-    A straight-line compiled trace executes one program, so a mixed batch
+    A compiled trace executes one program, so a mixed batch
     is *segmented*: requests are stable-sorted by op_id (preserving
     arrival order within an op — the ordering atomics serialize by), each
     contiguous segment runs through its own compiled trace against the
@@ -612,11 +607,13 @@ def superop_report(op: VerifiedOperator) -> Dict[str, object]:
 # ---------------------------------------------------------------------------
 
 class _Tracer:
-    """Emits the predicated straight-line trace of one verified operator.
+    """Emits the predicated trace of one verified operator.
 
     Mutable traced state: the 16 register lanes, the flattened shared
-    memory pool, and the halt/ret/status/step accumulators.  Control flow
-    exists only at Python time (the unroll), never in the lowered program.
+    memory pool, the halt/ret/status/step accumulators and the fault
+    record (:data:`_CARRIED` — what a loop trip carries).  Branches exist
+    only at Python time; the one control flow in the lowered program is
+    the ``while_loop`` of each generic loop.
     """
 
     def __init__(self, *, instrs, loops, base, mask, n_dev, pool_words,
@@ -645,23 +642,33 @@ class _Tracer:
         self.ret = zero
         self.status = jnp.full(batch, isa.STATUS_FELL_OFF, jnp.int64)
         self.steps = zero
-        # fault record: each faulting site appends its (B,) fault lanes
-        # plus the runtime address (and device / chain-step, when not
-        # static) to `pending`; a faulted lane halts, so at most one
-        # site fires per lane and the whole record reduces to one fused
-        # sum at trace finalization (`_finalize_fault`) — the hot path
-        # pays no per-site selects at all.  `sites` is the static side
-        # table the (pc, opcode, dev) columns are recovered from; its
-        # last column is the site *kind* (0 plain instruction, 1 gather
-        # chain, 2 scatter-reduce, 3 map loop, 4 zip loop — fused sites
-        # recover pc/opcode from the latched body-instruction index).
+        # fault record: a faulted lane halts, so at most one site fires
+        # per lane and every latched column is a masked sum — each site
+        # adds its index + 1, the runtime address (and the device /
+        # chain step, when not static) under its fault lanes
+        # (`_record_fault`), with no per-site selects.  `sites` is the
+        # static side table the (pc, opcode, dev) columns are recovered
+        # from at finalization (`_finalize_fault`); its last column is
+        # the site *kind* (0 plain instruction, 1 gather chain,
+        # 2 scatter-reduce, 3 map loop, 4 zip loop — fused sites recover
+        # pc/opcode from the latched body-instruction index).  A site
+        # inside a loop body is traced once and serves every trip.
         self.sites: List[Tuple[int, int, int, int]] = []
-        self.pending: List[Tuple] = []
+        self.f_site = self.f_addr = self.f_dev = self.f_aux = zero
 
     # -- small helpers ---------------------------------------------------
 
     def _full(self, v) -> jnp.ndarray:
         return jnp.full(self.B, v, jnp.int64)
+
+    def _carried(self) -> Tuple:
+        return (tuple(self.regs),) + tuple(getattr(self, n)
+                                           for n in _CARRIED)
+
+    def _restore(self, state: Tuple) -> None:
+        self.regs = list(state[0])
+        for n, v in zip(_CARRIED, state[1:]):
+            setattr(self, n, v)
 
     def set_reg(self, idx: int, val, p) -> None:
         idx &= _REG_MASK
@@ -684,6 +691,20 @@ class _Tracer:
 
     # -- runtime protection ------------------------------------------------
 
+    def _record_fault(self, site, f, addr, dev=None, aux=None) -> None:
+        """Halt the lanes ``f``, add the static ``site`` row
+        (pc, opcode, dev column, kind) to ``sites`` and its runtime
+        columns to the fault record under ``f`` (see ``__init__``)."""
+        self.halted = self.halted | f
+        fi = f.astype(jnp.int64)
+        self.f_site = self.f_site + fi * (len(self.sites) + 1)
+        self.f_addr = self.f_addr + fi * addr
+        if dev is not None:
+            self.f_dev = self.f_dev + fi * dev
+        if aux is not None:
+            self.f_aux = self.f_aux + fi * aux
+        self.sites.append(site)
+
     def _latch_fault(self, p, flt, pc, opcode, addr, dev=None):
         """Latch a protection fault on lanes ``p & flt``: halt them,
         record the static site index plus the runtime address (and the
@@ -694,18 +715,15 @@ class _Tracer:
 
         ``dev``: None when the resolved device is statically the lane's
         home, an int when it is a static device id, or a traced (B,)
-        array (kept live for the finalization reduction)."""
+        array (latched into the record's device column)."""
         f = p & flt
-        self.halted = self.halted | f
-        k = len(self.sites)
         if dev is None:
             devcol, dtr = _DEV_HOME, None
         elif isinstance(dev, int):
             devcol, dtr = dev, None
         else:
             devcol, dtr = _DEV_LATCHED, dev
-        self.sites.append((pc, int(opcode), devcol, 0))
-        self.pending.append((k, f, addr, dtr, None))
+        self._record_fault((pc, int(opcode), devcol, 0), f, addr, dtr)
         return p ^ f
 
     def _word_fault(self, ins, p, pc):
@@ -896,13 +914,11 @@ class _Tracer:
                 kstar == 1, jnp.take_along_axis(ids_off, jsc, axis=1)[:, 0],
                 jnp.where(kstar == 2,
                           jnp.take_along_axis(ids, jsc, axis=1)[:, 0], a3))
-            self.halted = self.halted | flt
             # body starts at pc+1, so pc of body instruction k* is
             # loop_pc + k* — recovered at finalization from the chain's
-            # latched k* (the aux column of the pending record)
-            k_site = len(self.sites)
-            self.sites.append((g.loop_pc, 0, _DEV_HOME, 1))
-            self.pending.append((k_site, flt, faddr, None, kstar))
+            # latched k* (the record's aux column)
+            self._record_fault((g.loop_pc, 0, _DEV_HOME, 1), flt, faddr,
+                               aux=kstar)
             m_eff = jnp.where(flt, js, m)
             live = valid & (jj < m_eff[:, None])
         else:
@@ -1037,10 +1053,8 @@ class _Tracer:
             faddr = jnp.where(
                 kstar == 1, jnp.take_along_axis(src_off, jsc, axis=1)[:, 0],
                 jnp.take_along_axis(acc_off, jsc, axis=1)[:, 0])
-            self.halted = self.halted | flt
-            k_site = len(self.sites)
-            self.sites.append((sr.loop_pc, 0, _DEV_HOME, 2))
-            self.pending.append((k_site, flt, faddr, None, kstar))
+            self._record_fault((sr.loop_pc, 0, _DEV_HOME, 2), flt, faddr,
+                               aux=kstar)
             m_eff = jnp.where(flt, js, m)
         else:
             flt = jnp.zeros(B, bool)
@@ -1122,11 +1136,9 @@ class _Tracer:
                 kstar == store_k,
                 jnp.take_along_axis(dst_off, jsc, axis=1)[:, 0],
                 jnp.take_along_axis(src_off, jsc, axis=1)[:, 0])
-            self.halted = self.halted | flt
-            k_site = len(self.sites)
-            self.sites.append((ml.loop_pc, 0, _DEV_HOME,
-                               4 if ml.is_zip else 3))
-            self.pending.append((k_site, flt, faddr, None, kstar))
+            self._record_fault((ml.loop_pc, 0, _DEV_HOME,
+                                4 if ml.is_zip else 3), flt, faddr,
+                               aux=kstar)
             m_eff = jnp.where(flt, js, m)
         else:
             flt = jnp.zeros(B, bool)
@@ -1230,19 +1242,42 @@ class _Tracer:
                         self._fused_map_loop(ml, m, p)
                         pc = body_hi
                         continue
-                broken = jnp.zeros(self.B, bool)
-                for it in range(cap):
-                    it_pred = pred & (it < m) & ~broken
-                    esc = self.emit_segment(l.start, body_hi, it_pred)
-                    for tgt, ep in esc.items():
-                        broken = broken | ep
-                        pred = pred & ~ep
-                        if tgt < hi:
-                            resume[tgt] = resume.get(
-                                tgt, jnp.zeros(self.B, bool)) | ep
-                        else:
-                            escapes[tgt] = escapes.get(
-                                tgt, jnp.zeros(self.B, bool)) | ep
+                # one while_loop trip per iteration, while some lane is
+                # live; the lanes that jump out of the body (the "break")
+                # collect per static target
+                exits = sorted({
+                    j + 1 + self.instrs[j].imm2
+                    for j in range(l.start, body_hi)
+                    if self.instrs[j].op == Op.JUMP
+                    and j + 1 + self.instrs[j].imm2 >= body_hi})
+                no = jnp.zeros(self.B, bool)
+                i_halted = 1 + _CARRIED.index("halted")
+
+                def live(it, state, gone):
+                    broken = functools.reduce(jnp.logical_or, gone, no)
+                    return pred & (it < m) & ~broken & ~state[i_halted]
+
+                def more(carry):
+                    it, state, gone = carry
+                    return (it < cap) & jnp.any(live(it, state, gone))
+
+                def trip(carry):
+                    it, state, gone = carry
+                    self._restore(state)
+                    esc = self.emit_segment(l.start, body_hi,
+                                            live(it, state, gone))
+                    assert set(esc) <= set(exits), (esc.keys(), exits)
+                    return it + 1, self._carried(), tuple(
+                        g | esc.get(t, no) for t, g in zip(exits, gone))
+
+                _, state, gone = lax.while_loop(
+                    more, trip, (jnp.int64(0), self._carried(),
+                                 (no,) * len(exits)))
+                self._restore(state)
+                for tgt, ep in zip(exits, gone):
+                    pred = pred & ~ep
+                    out = resume if tgt < hi else escapes
+                    out[tgt] = out.get(tgt, no) | ep
                 pc = body_hi
                 continue
 
@@ -1299,30 +1334,17 @@ class _Tracer:
 # ---------------------------------------------------------------------------
 
 def _finalize_fault(tracer: _Tracer):
-    """Reduce the pending per-site fault lanes to (pc, opcode, addr,
-    dev) rows and apply STATUS_PROT_FAULT once.  A faulting lane halts
-    at its first fault, so the per-site lane masks are mutually
-    exclusive and every latched column is a plain masked sum — one
-    fused elementwise reduction here instead of per-site selects on
-    the hot path.  (A latched fault also implies the lane halted
-    before any RET could retire it, so the single status override is
-    equivalent to a per-site status write.)"""
+    """Turn the latched fault record into (pc, opcode, addr, dev) rows
+    and apply STATUS_PROT_FAULT once.  (A latched fault implies the
+    lane halted before any RET could retire it, so the single status
+    override is equivalent to a per-site status write.)"""
     B = tracer.B
-    if not tracer.pending:
+    if not tracer.sites:
         none = jnp.zeros(B, jnp.int64)
         return tracer.status, jnp.stack([none - 1, none, none, none],
                                         axis=1)
-    zero = jnp.zeros(B, jnp.int64)
-    site, addr, devp, aux = zero, zero, zero, zero
-    for k, f, a, d, x in tracer.pending:
-        fi = f.astype(jnp.int64)
-        site = site + fi * (k + 1)
-        addr = addr + fi * a
-        if d is not None:
-            devp = devp + fi * d
-        if x is not None:
-            aux = aux + fi * x
-    site = site - 1
+    site = tracer.f_site - 1
+    addr, devp, aux = tracer.f_addr, tracer.f_dev, tracer.f_aux
     pc_t, op_t, dev_t, kind_t = (jnp.asarray(np.asarray(col, np.int64))
                                  for col in zip(*tracer.sites))
     sidx = jnp.maximum(site, 0)
@@ -1356,8 +1378,7 @@ def build_compiled(op: VerifiedOperator, regions: RegionTable,
                    n_devices: int, batch: int, *, impl: str = "xla",
                    superops: bool = True, double_buffer: bool = False,
                    protect: bool = True, check_failed: bool = True,
-                   noconflict: bool = False,
-                   unroll_limit: int = DEFAULT_UNROLL_LIMIT):
+                   noconflict: bool = False):
     """Trace-compile a verified operator; returns a jit-compiled
     ``f(mem, params, homes, failed) -> vm.VMResult`` with batched fields
     (the same signature as :func:`vm.build_batched_vm`).
@@ -1384,9 +1405,6 @@ def build_compiled(op: VerifiedOperator, regions: RegionTable,
     It unlocks the scatter-reduce superoperator fusion, whose
     snapshot-read lowering is exact only under that proof.
     """
-    reason = why_not_compilable(op, unroll_limit)
-    if reason is not None:
-        raise CompileError(reason)
     instrs = isa.decode_program(op.code)
     loops = {l.pc: l for l in op.loops}
     base, mask, _ = regions.as_arrays()

@@ -242,9 +242,9 @@ BF2_CABLE_RTT_US = 1.9         # back-to-back DAC cable [paper §2.2]
 # The registry's ``mode="auto"`` has to pick an execution engine per call:
 # the scalar interpreter (one launch per request), the batch-parallel
 # lockstep interpreter (one launch per wave, exact under contention), the
-# trace-compiled straight-line path (fastest, needs a compilable CFG and
-# a conflict-free wave), or — for mixed-op waves — the one-launch mixed
-# engine vs. stable-sort-and-segment through the compiled traces.  The
+# trace-compiled path (fastest, needs a conflict-free wave), or — for
+# mixed-op waves — the one-launch mixed engine vs. stable-sort-and-segment
+# through the compiled traces.  The
 # analytical model below predicts wall-clock per call from batch size,
 # trace length, op-mix composition, and a contention-rate hint, using
 # per-engine launch/step constants calibrated against the measured
@@ -335,7 +335,11 @@ class EngineCost:
 
     def compiled_us(self, batch: int, trace_len: int, *,
                     cached: bool = True) -> float:
-        """One straight-line launch over the unrolled trace."""
+        """One launch of the predicated trace, priced at its worst case:
+        every step position of ``trace_len`` (a loop body once per trip
+        of its cap, as the verifier's step bound counts it).  Not
+        modeled: a loop trip's own fixed cost, and the early exit of a
+        loop once no lane is live in it."""
         return self._miss(cached) + self.launch_us \
             + trace_len * (self.cstep_us + batch * self.clane_us)
 
@@ -440,7 +444,6 @@ class SegmentStats:
 
     size: int
     step_bound: int
-    compilable: bool
     batched_cached: bool = True    # lockstep engine built at this size?
     compiled_cached: bool = True   # compiled trace built at this size?
 
@@ -673,7 +676,6 @@ class DispatchCostModel:
     # -- single-op waves --------------------------------------------------
 
     def choose_batched(self, *, batch: int, step_bound: int,
-                       compilable: bool,
                        contention_rate: float = 0.0,
                        chain_iters: int = 0,
                        batched_cached: bool = True,
@@ -683,7 +685,7 @@ class DispatchCostModel:
                        static_noconflict: bool = False) -> DispatchDecision:
         """Pick the engine for a single-op wave: "batched" (the lockstep
         interpreter; at B=1 this *is* the classic scalar MP datapath),
-        "compiled" (the straight-line trace), or "compiled_dbuf" (the
+        "compiled" (the predicated trace), or "compiled_dbuf" (the
         double-buffered gather-chain schedule — a candidate only when
         the operator has gather chains, ``chain_iters`` > 0, and wins
         only when they are long enough for the learned overlap term to
@@ -704,7 +706,7 @@ class DispatchCostModel:
                                                  contention_rate,
                                                  cached=batched_cached)
                  * self.dispatch_scale(key, "batched")}
-        if compilable and contention_rate <= 0.0:
+        if contention_rate <= 0.0:
             costs["compiled"] = self.cost.compiled_us(
                 batch, step_bound, cached=compiled_cached) \
                 * self.dispatch_scale(key, "compiled")
@@ -729,7 +731,7 @@ class DispatchCostModel:
             best = self.cost.batched_us(s.size, s.step_bound,
                                         contention_rate,
                                         cached=s.batched_cached)
-            if s.compilable and contention_rate <= 0.0:
+            if contention_rate <= 0.0:
                 best = min(best,
                            self.cost.compiled_us(
                                s.size, s.step_bound,

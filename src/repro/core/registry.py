@@ -5,8 +5,9 @@ and the 256-entry ``op_id -> start_pc`` dispatch table (paper §3).
 ``register()`` is the eBPF-load moment: compile output goes through the
 static verifier against the *tenant's* grant; only then does the operator
 get a slot.  Registration is also the trace-compile moment: the slot
-records whether the operator's CFG admits the interpreter-free fast path
-(``core/compile``), so the data path can dispatch with no further checks.
+records the superoperators the interpreter-free fast path
+(``core/compile``) will fuse, so the data path can dispatch with no
+further checks.
 
 The data path is *internal* engine plumbing behind the queue-pair
 endpoint surface (``core/endpoint``): ``_invoke()`` is single-request
@@ -67,8 +68,8 @@ class Slot:
 
     ``interp``   single-request lax.while_loop interpreter (always there);
     ``batched``  batch-parallel interpreter, B requests per XLA launch;
-    ``compiled`` trace-compiled straight-line fast path (when the CFG
-                 admits one — ``compilable`` / ``compile_reason``).
+    ``compiled`` trace-compiled fast path (every verified operator has
+                 one).
     """
 
     op_id: int
@@ -76,7 +77,6 @@ class Slot:
     verified: VerifiedOperator
     start_pc: int
     regions: RegionTable
-    compile_reason: Optional[str] = None
     n_gather_chains: int = 0
     # Summed static caps of the *double-bufferable* gather chains only
     # (cap > compile.DBUF_CHUNK — the engine chunks per chain, so a
@@ -90,10 +90,6 @@ class Slot:
     # gather-chain matcher bailed on it.
     superops: Tuple[Tuple[str, int], ...] = ()
     superop_near_miss: Optional[str] = None
-
-    @property
-    def compilable(self) -> bool:
-        return self.compile_reason is None
 
     @property
     def footprint(self) -> Optional[access.OpFootprint]:
@@ -141,10 +137,6 @@ class Slot:
                  impl: str = "xla", double_buffer: bool = False,
                  block: bool = True,
                  static_noconflict: bool = False) -> vm.BatchedInvokeResult:
-        if not self.compilable:
-            raise ValueError(
-                f"op {self.op_id} has no compiled entry point: "
-                f"{self.compile_reason}")
         return tcompile.invoke_compiled(self.verified, self.regions, mem,
                                         params, homes=homes, failed=failed,
                                         impl=impl,
@@ -234,23 +226,9 @@ class OperatorRegistry:
         report = tcompile.superop_report(verified)
         matched = tuple(report["matched"])  # type: ignore[arg-type]
         near_miss = report["near_miss"]
-        reason = tcompile.why_not_compilable(verified)
-        if reason is not None:
-            # interp-only slots surface the full analysis in the reason
-            # itself — the one string a "why is this slow" caller reads
-            extra = ["footprint: "
-                     + access.describe_footprint(verified.footprint,
-                                                 self.regions)]
-            if matched:
-                extra.append("superops: " + ", ".join(
-                    f"{kind}@pc{pc}" for kind, pc in matched))
-            if near_miss is not None:
-                extra.append(f"superop near-miss: {near_miss}")
-            reason = "; ".join([reason] + extra)
         self._slots[op_id] = Slot(
             op_id=op_id, tenant=tenant, verified=verified,
             start_pc=self._store_used, regions=self.regions,
-            compile_reason=reason,
             n_gather_chains=len(chains),
             chain_iters=sum(g.cap for g in chains
                             if g.cap > tcompile.DBUF_CHUNK),
@@ -348,7 +326,7 @@ class OperatorRegistry:
             n_dev = int(mem.shape[0])
             decision = self.cost_model.choose_batched(
                 batch=1, step_bound=slot.verified.step_bound,
-                compilable=slot.compilable, key=op_id,
+                key=op_id,
                 batched_cached=vm.engine_cached(
                     slot.verified, self.regions, n_dev, 1),
                 compiled_cached=tcompile.compiled_cached(
@@ -403,7 +381,7 @@ class OperatorRegistry:
         if mode == "auto":
             decision = self.cost_model.choose_batched(
                 batch=B, step_bound=slot.verified.step_bound,
-                compilable=slot.compilable, key=op_id,
+                key=op_id,
                 contention_rate=contention_rate,
                 chain_iters=slot.chain_iters,
                 static_noconflict=nc,
@@ -445,7 +423,6 @@ class OperatorRegistry:
             v = self._slots[seg.op_id].verified
             out.append(SegmentStats(
                 size=seg.size, step_bound=v.step_bound,
-                compilable=self._slots[seg.op_id].compilable,
                 batched_cached=vm.engine_cached(v, self.regions, n_dev,
                                                 seg.size),
                 compiled_cached=tcompile.compiled_cached(
@@ -723,7 +700,6 @@ class OperatorRegistry:
         lines = []
         for op_id, slot in sorted(self._slots.items()):
             p = slot.verified.program
-            fast = "compiled" if slot.compilable else "interp-only"
             chains = f" gather-chains={slot.n_gather_chains}" \
                 if slot.n_gather_chains else ""
             lines.append(
@@ -731,7 +707,7 @@ class OperatorRegistry:
                 f"{p.name:<20s} {p.n_instr:3d} instrs  "
                 f"bound={slot.verified.step_bound:<8d} "
                 f"regions r={p.regions_read} w={p.regions_written} "
-                f"[{fast}{chains}]")
+                f"[compiled{chains}]")
             # registration-time analysis artifacts: derived footprint,
             # matched superoperators, nearest superop near-miss
             lines.append("         " + slot.describe_analysis())

@@ -13,7 +13,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ArchConfig, ShapeSpec, cell_applicable
@@ -22,7 +22,7 @@ from repro.models.attention import PagedKV
 from repro.models.blocks import BlockCache
 from repro.models.mamba import MambaCache
 from repro.models.rwkv import RWKVCache
-from repro.launch.mesh import dp_axes, dp_size
+from repro.launch.mesh import auto_view, dp_axes, dp_size
 from repro.training.optimizer import AdamWConfig, AdamWState, warmup_cosine
 from repro.training.train_step import TrainState, make_train_step
 
@@ -113,6 +113,21 @@ def _cache_structs_and_specs(cfg: ArchConfig, b: int, maxp: int, dp, dpsz,
 def make_cell(cfg: ArchConfig, shape: ShapeSpec, mesh, *,
               state_bits: int = 32, variant: str = "baseline"
               ) -> Optional[Cell]:
+    """The cell's step, args and shardings, built on the ``Auto`` view
+    of ``mesh`` (:func:`repro.launch.mesh.auto_view`).  A mesh the caller
+    built with ``Explicit`` axes (``jax.make_mesh``'s default) gets a
+    step wrapped in ``auto_axes``, so it runs under that mesh too."""
+    explicit = tuple(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                     if t == AxisType.Explicit)
+    cell = _make_cell(cfg, shape, auto_view(mesh), state_bits, variant)
+    if cell is not None and explicit:
+        cell.fn = jax.sharding.auto_axes(cell.fn, axes=explicit,
+                                         out_sharding=cell.out_specs)
+    return cell
+
+
+def _make_cell(cfg: ArchConfig, shape: ShapeSpec, mesh, state_bits: int,
+               variant: str) -> Optional[Cell]:
     ok, why = cell_applicable(cfg, shape)
     if not ok:
         return None

@@ -22,12 +22,10 @@ import json           # noqa: E402
 import time           # noqa: E402
 import traceback      # noqa: E402
 
-import jax            # noqa: E402
-
 from repro.configs import (ARCH_IDS, SHAPES, cell_applicable,  # noqa: E402
                            get_config)
 from repro.launch import cells as cells_mod                    # noqa: E402
-from repro.launch.mesh import make_production_mesh             # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.models import transformer as tf                     # noqa: E402
 from repro.roofline import analysis as ra                      # noqa: E402
 
@@ -36,8 +34,8 @@ def make_mesh_for(args, multi_pod: bool):
     if args.devices_override:
         n = args.devices_override
         if multi_pod:
-            return jax.make_mesh((2, n // 8, 4), ("pod", "data", "model"))
-        return jax.make_mesh((n // 4, 4), ("data", "model"))
+            return make_mesh((2, n // 8, 4), ("pod", "data", "model"))
+        return make_mesh((n // 4, 4), ("data", "model"))
     return make_production_mesh(multi_pod=multi_pod)
 
 

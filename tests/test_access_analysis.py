@@ -416,7 +416,7 @@ def test_verifier_diagnostics_carry_operator_name():
 
 
 # ---------------------------------------------------------------------------
-# Registry surface: toggle, dump, compile_reason, cross-op fusion
+# Registry surface: toggle, dump, cross-op fusion
 # ---------------------------------------------------------------------------
 
 def test_static_analysis_toggle_disables_proofs():
@@ -433,26 +433,6 @@ def test_dump_reports_footprints_and_superops():
     assert "footprint:" in d
     assert "⊤" in d                       # chase's top shows up
     assert "superop near-miss: pc" in d   # window's non-chain loop
-
-
-def test_compile_reason_carries_analysis():
-    rt = _table()
-    reg, _ = _registry(rt)
-
-    def build(rt):  # step bound past the unroll limit -> interp-only
-        b = OperatorBuilder("bigloop", n_params=1, regions=rt)
-        v = b.reg()
-        with b.loop(4096):
-            b.load(v, "src", b.param(0))
-            b.alu(v, v, Alu.ADD, 1)
-        b.ret(v)
-        return b.build()
-
-    op = reg.register("t", build(rt))
-    slot = reg[op]
-    assert not slot.compilable
-    assert "footprint:" in slot.compile_reason
-    assert "superop near-miss: pc" in slot.compile_reason
 
 
 def test_cross_op_fusion_of_identical_programs():
@@ -481,18 +461,17 @@ def test_cross_op_fusion_of_identical_programs():
 
 def test_choose_batched_proof_overrides_contention():
     m = DispatchCostModel()
-    guess = m.choose_batched(batch=64, step_bound=32, compilable=True,
-                             contention_rate=0.9)
+    guess = m.choose_batched(batch=64, step_bound=32, contention_rate=0.9)
     assert "compiled" not in guess.costs  # guess blocks the trace
-    proven = m.choose_batched(batch=64, step_bound=32, compilable=True,
-                              contention_rate=0.9, static_noconflict=True)
+    proven = m.choose_batched(batch=64, step_bound=32, contention_rate=0.9,
+                              static_noconflict=True)
     assert proven.static_noconflict and proven.contention_rate == 0.0
     assert "compiled" in proven.costs
 
 
 def test_choose_mixed_proof_enables_segmented():
     m = DispatchCostModel()
-    segs = [SegmentStats(size=32, step_bound=16, compilable=True)] * 2
+    segs = [SegmentStats(size=32, step_bound=16)] * 2
     guess = m.choose_mixed(segments=segs, contention_rate=0.5)
     assert "segmented" not in guess.costs
     proven = m.choose_mixed(segments=segs, contention_rate=0.5,

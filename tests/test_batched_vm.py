@@ -12,6 +12,7 @@ import pytest
 
 from repro.core import compile as tc
 from repro.core import isa, memory, pyvm, vm
+from repro.core.isa import Alu
 from repro.core.memory import Grant, merge_tables
 from repro.core import operators as ops
 from repro.core.program import OperatorBuilder
@@ -188,7 +189,7 @@ def test_contended_dist_lock_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# Trace-compiled fast path vs interpreter — every compilable seed operator
+# Trace-compiled fast path vs interpreter — every seed operator
 # ---------------------------------------------------------------------------
 
 def _compiled_check(name, vop, rt, mem, params, home=0, failed=None):
@@ -207,11 +208,76 @@ def test_compiled_equals_pyvm_graph_walk():
     w = ops.GraphWalk(n_nodes=128, max_depth=32)
     rt = w.regions()
     vop = verify(w.build(rt), grant=Grant.all_of(rt), regions=rt)
-    assert tc.compilable(vop)
     mem = memory.make_pool(1, rt)
     order = w.populate(mem, rt)
     for depth in (0, 1, 7, 31):
         _compiled_check("graph", vop, rt, mem, [int(order[5]) * 8, depth])
+
+
+def test_compiled_protected_graph_walk_at_benchmark_depth():
+    """The benchmark's GraphWalk cap (max_depth=64) under runtime
+    protection.  Each hop's address and halt mask feed each other, so a
+    fully unrolled walk ran in time exponential in the cap on XLA:CPU;
+    a loop trip per iteration keeps it linear."""
+    w = ops.GraphWalk(n_nodes=128, max_depth=64)
+    rt = w.regions()
+    vop = verify(w.build(rt), grant=Grant.all_of(rt), regions=rt)
+    mem = memory.make_pool(1, rt)
+    order = w.populate(mem, rt)
+    start = int(order[5]) * 8
+    for depth in (0, 10, 63):
+        _compiled_check("graph64", vop, rt, mem, [start, depth])
+    # a torn pointer read at hop 6 faults hop 7, mid-loop: the fault row
+    # latched inside the loop matches the oracle's
+    torn = mem.copy()
+    torn[0, rt["graph"].base + int(order[10]) * 8 + 1] = -77
+    _compiled_check("graph64 torn", vop, rt, torn, [start, 63])
+    r1 = pyvm.run(vop, rt, torn.copy(), [start, 63])
+    rc = tc.invoke_compiled(vop, rt, torn.copy(), [[start, 63]])
+    assert r1.status == isa.STATUS_PROT_FAULT and r1.fault.addr == -76
+    assert rc.fault_at(0) == r1.fault
+
+
+def test_compiled_loop_lanes_leave_at_different_trips():
+    """The lanes of one wave leave a loop at different trips: by their
+    trip count (0, 5), by a break, by a RET in the body, by a fault
+    (a load past the region) or at the cap.  The compiled loop runs
+    until its last live lane is done and matches pyvm lane by lane."""
+    rt = memory.packed_table([("data", 64)])
+    b = OperatorBuilder("exits", n_params=3, regions=rt)
+    acc = b.const(0)
+    k = b.const(0)
+    v = b.reg()
+    out = b.mklabel("out")
+    stay = b.mklabel("stay")
+    with b.loop((b.param(0), 100)):
+        b.jump(out, k, Alu.EQ, b.param(1))          # break
+        b.jump(stay, k, Alu.NE, b.param(2))
+        b.ret(acc)                                  # RET inside the body
+        b.bind(stay)
+        b.load(v, "data", k)                        # faults at k = 64
+        b.add(acc, acc, v)
+        b.add(k, k, 1)
+    b.bind(out)
+    b.ret(acc)
+    vop = verify(b.build(), grant=Grant.all_of(rt), regions=rt)
+    mem = memory.make_pool(1, rt)
+    mem[0, :64] = np.arange(64) * 3 + 1
+    params = [[0, -1, -1], [5, -1, -1], [100, 10, -1], [100, -1, 20],
+              [100, -1, -1], [50, -1, -1]]
+    rc = tc.invoke_compiled(vop, rt, mem.copy(), params)
+    stats = []
+    for lane, p in enumerate(params):
+        r1 = pyvm.run(vop, rt, mem.copy(), p)
+        stats.append(r1.status)
+        assert rc.ret[lane] == r1.ret, lane
+        assert rc.status[lane] == r1.status, lane
+        assert rc.steps[lane] == r1.steps, lane
+        assert np.array_equal(rc.regs[lane], np.array(r1.regs)), lane
+        assert rc.fault_at(lane) == r1.fault, lane
+    assert stats.count(isa.STATUS_PROT_FAULT) == 1
+    assert np.array_equal(rc.mem, mem)
+
 
 
 def test_compiled_equals_pyvm_ptw3():
@@ -366,7 +432,6 @@ def test_registry_slot_entry_points():
     reg.add_tenant(Grant.all_of(rt, "alice"))
     op_id = reg.register("alice", w.build(rt, reply_param=True))
     slot = reg[op_id]
-    assert slot.compilable and slot.compile_reason is None
     mem = memory.make_pool(1, rt)
     order = w.populate(mem, rt)
     params = [[int(order[i]) * 8, 3, i * ops.NODE_WORDS] for i in range(4)]
@@ -514,14 +579,15 @@ def test_mixed_contended_store_cas_deterministic():
     assert np.array_equal(res.mem, res2.mem)
 
 
-def test_registry_interp_fallback_for_uncompilable():
-    """An operator over the unroll budget keeps the interpreter path."""
+def test_registry_compiles_long_loop():
+    """A loop far past any unroll budget (8000 trips) is traced once and
+    runs on the compiled path, equal to the batched interpreter."""
     rt = memory.packed_table([("data", 1024)])
     b = OperatorBuilder("big_loop", n_params=1, regions=rt)
-    i = b.const(0)
+    i = b.mov(b.reg(), b.param(0))
     v = b.reg()
     j = b.reg()
-    with b.loop(8000):                    # step bound >> unroll limit
+    with b.loop(8000):
         b.band(j, i, 1023)                # stay inside the 1024-word grant
         b.load(v, "data", j)
         b.add(i, i, 1)
@@ -529,11 +595,12 @@ def test_registry_interp_fallback_for_uncompilable():
     reg = OperatorRegistry(rt, max_steps=1 << 20)
     reg.add_tenant(Grant.all_of(rt, "t"))
     op_id = reg.register("t", b.build())
-    slot = reg[op_id]
-    assert not slot.compilable and "unroll" in slot.compile_reason
     mem = memory.make_pool(1, rt)
     mem[0, :1024] = np.arange(1024)
-    res = reg._invoke_batched(op_id, mem, [[0], [0]], mode="auto")
-    assert np.all(res.status == isa.STATUS_OK)
-    with pytest.raises(Exception):
-        slot.compiled(mem, [[0]])
+    params = [[0], [5]]
+    r_int = reg._invoke_batched(op_id, mem, params, mode="batched")
+    r_cmp = reg._invoke_batched(op_id, mem, params, mode="compiled")
+    assert list(r_cmp.ret) == [7999 & 1023, 8004 & 1023]
+    assert np.all(r_cmp.status == isa.STATUS_OK)
+    for f in ("ret", "status", "steps", "mem"):
+        assert np.array_equal(getattr(r_cmp, f), getattr(r_int, f)), f

@@ -14,7 +14,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 REPO = os.path.join(os.path.dirname(__file__), "..")
 
 
-def run_py(code: str, n_devices: int = 8, timeout: int = 900) -> str:
+def run_py(code: str, n_devices: int = 8, timeout: int = 240) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{n_devices}")
@@ -100,8 +100,31 @@ def test_production_mesh_shapes():
     assert "OK" in out
 
 
+def test_program_meshes_have_auto_axes():
+    """Every mesh the program builds, and the view make_cell builds on,
+    has Auto axes: the model's sharding hints steer the partitioner
+    there, where on Explicit axes they would be assertions."""
+    out = run_py("""
+        import jax
+        from jax.sharding import AxisType
+        from repro.launch.mesh import (auto_view, make_host_mesh,
+                                       make_production_mesh)
+        explicit = jax.make_mesh((2, 4), ("data", "model"))
+        assert set(explicit.axis_types) == {AxisType.Explicit}
+        view = auto_view(explicit)
+        assert set(view.axis_types) == {AxisType.Auto}
+        assert view.axis_names == explicit.axis_names
+        assert (view.devices == explicit.devices).all()
+        for m in (make_host_mesh(2, 4), make_production_mesh(),
+                  make_production_mesh(multi_pod=True)):
+            assert set(m.axis_types) == {AxisType.Auto}, m
+        print("OK")
+        """, n_devices=512)
+    assert "OK" in out
+
+
 @pytest.mark.slow
-def test_dryrun_small_mesh_cell():
+def test_dryrun_small_mesh_cell(tmp_path):
     """End-to-end dry-run of one train + one decode cell on 8 devices."""
     env = dict(os.environ)
     env["DRYRUN_DEVICES"] = "8"
@@ -109,12 +132,12 @@ def test_dryrun_small_mesh_cell():
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "internlm2-1.8b", "--mesh", "single",
-         "--devices-override", "8", "--out", "/tmp/dryrun_test8"],
-        capture_output=True, text=True, env=env, timeout=1800, cwd=REPO)
+         "--devices-override", "8", "--out", str(tmp_path)],
+        capture_output=True, text=True, env=env, timeout=240, cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "0 fail" in out.stdout.split("complete:")[1]
     rec = json.load(open(
-        "/tmp/dryrun_test8/internlm2-1.8b__train_4k__pod16x16_ovr8.json"))
+        tmp_path / "internlm2-1.8b__train_4k__pod16x16_ovr8.json"))
     assert rec["status"] == "ok"
     assert rec["roofline"]["hlo_flops"] > 1e15
     assert rec["memory"]["argument_size_in_bytes"] > 0
@@ -197,5 +220,5 @@ def test_sharded_paged_decode_matches_baseline():
             err = np.abs(outs["baseline"] - outs[v]).max()
             assert err < 2e-4, (v, err)
         print("OK")
-        """, timeout=1500)
+        """)
     assert "OK" in out

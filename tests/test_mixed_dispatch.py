@@ -58,7 +58,7 @@ def test_op_mix_entropy():
 
 def test_choose_batched_prefers_compiled_when_clean():
     cm = DispatchCostModel()
-    d = cm.choose_batched(batch=256, step_bound=40, compilable=True)
+    d = cm.choose_batched(batch=256, step_bound=40)
     assert d.mode == "compiled"
     assert d.costs["compiled"] < d.costs["batched"]
 
@@ -67,28 +67,21 @@ def test_choose_batched_contention_forces_interpreter():
     """The compiled trace cannot serialize contended non-atomic writes,
     so any contention hint must keep the wave on the exact interpreter."""
     cm = DispatchCostModel()
-    d = cm.choose_batched(batch=256, step_bound=40, compilable=True,
-                          contention_rate=0.5)
+    d = cm.choose_batched(batch=256, step_bound=40, contention_rate=0.5)
     assert d.mode == "batched"
     assert "compiled" not in d.costs
 
 
-def test_choose_batched_uncompilable():
-    cm = DispatchCostModel()
-    d = cm.choose_batched(batch=8, step_bound=10000, compilable=False)
-    assert d.mode == "batched"
-
-
 def test_choose_mixed_few_big_segments_vs_many_small():
     cm = DispatchCostModel()
-    # 4 big compilable segments: per-segment compiled launches win
-    big = [SegmentStats(size=256, step_bound=40, compilable=True)] * 4
+    # 4 big segments: per-segment compiled launches win
+    big = [SegmentStats(size=256, step_bound=40)] * 4
     d = cm.choose_mixed(segments=big)
     assert d.mode == "segmented"
     assert d.entropy_bits == pytest.approx(2.0)
     # 64 tiny segments: per-segment launch overhead dominates, the
     # one-launch mixed engine wins
-    tiny = [SegmentStats(size=2, step_bound=40, compilable=True)] * 64
+    tiny = [SegmentStats(size=2, step_bound=40)] * 64
     d2 = cm.choose_mixed(segments=tiny)
     assert d2.mode == "mixed"
     assert d2.entropy_bits == pytest.approx(6.0)
@@ -100,7 +93,7 @@ def test_choose_mixed_contention_pins_round_robin():
     reference round-robin interleaving for contended footprints — so a
     contended wave must stay on the one-launch mixed engine."""
     cm = DispatchCostModel()
-    segs = [SegmentStats(size=128, step_bound=40, compilable=True)] * 2
+    segs = [SegmentStats(size=128, step_bound=40)] * 2
     clean = cm.choose_mixed(segments=segs)
     assert "segmented" in clean.costs
     contended = cm.choose_mixed(segments=segs, contention_rate=0.5)
@@ -112,10 +105,10 @@ def test_choose_batched_charges_uncached_compile():
     """An engine not yet built at this batch size costs an (amortized)
     XLA compile; a warm alternative should win until both are built."""
     cm = DispatchCostModel()
-    cold = cm.choose_batched(batch=64, step_bound=40, compilable=True,
-                             batched_cached=True, compiled_cached=False)
+    cold = cm.choose_batched(batch=64, step_bound=40, batched_cached=True,
+                             compiled_cached=False)
     assert cold.mode == "batched"
-    warm = cm.choose_batched(batch=64, step_bound=40, compilable=True)
+    warm = cm.choose_batched(batch=64, step_bound=40)
     assert warm.mode == "compiled"
     amortized = (EngineCost().compile_us
                  / EngineCost().compile_amortization)
@@ -129,18 +122,17 @@ def test_choose_batched_double_buffer_crossover():
     lose) stay on the monolithic trace; contention excludes both."""
     cm = DispatchCostModel()
     long_ = cm.choose_batched(batch=256, step_bound=5 * 64 + 6,
-                              compilable=True, chain_iters=64)
+                              chain_iters=64)
     assert long_.mode == "compiled_dbuf"
     assert long_.costs["compiled_dbuf"] < long_.costs["compiled"]
     short = cm.choose_batched(batch=256, step_bound=5 * 4 + 6,
-                              compilable=True, chain_iters=4)
+                              chain_iters=4)
     assert short.mode == "compiled"
     assert short.costs["compiled_dbuf"] > short.costs["compiled"]
-    no_chain = cm.choose_batched(batch=256, step_bound=40,
-                                 compilable=True)
+    no_chain = cm.choose_batched(batch=256, step_bound=40)
     assert "compiled_dbuf" not in no_chain.costs
     contended = cm.choose_batched(batch=256, step_bound=5 * 64 + 6,
-                                  compilable=True, chain_iters=64,
+                                  chain_iters=64,
                                   contention_rate=0.5)
     assert contended.mode == "batched"
     assert "compiled_dbuf" not in contended.costs
@@ -171,10 +163,10 @@ def test_choose_placement_prices_single_as_best_local_dispatch():
     prematurely.  With the dense plan's segment stats the single-chip
     side is the min of mixed and segmented and keeps the wave local."""
     cm = DispatchCostModel()
-    # 4 big compilable segments, total B=1024, long traces: segmented
+    # 4 big segments, total B=1024, long traces: segmented
     # crushes mixed locally, and sharding (collective tax per step)
     # beats *mixed* but not *segmented*
-    segs = [SegmentStats(size=256, step_bound=60, compilable=True)] * 4
+    segs = [SegmentStats(size=256, step_bound=60)] * 4
     kw = dict(batch=1024, n_devices=8, step_bound=60,
               batch_per_device=128)
     old = cm.choose_placement(**kw)                    # no segment stats
