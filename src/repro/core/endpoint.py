@@ -38,7 +38,12 @@ in the queues.  This module is that surface in software:
     :meth:`WaveHandle.wait`.  Waves retire strictly in launch order, so
     per-session FIFO survives any number of waves in flight; each
     retired CQE carries a frozen :class:`CompletionEvent` with status,
-    return value, and retire timestamp.
+    return value, and retire timestamp.  Retiring brings back each
+    post's per-lane result only: the pool stays on the device from wave
+    to wave, region reads move just their words, and only a
+    control-path access (``host_mem``, ``write_region``, injected
+    corruption, ``rehome``) copies it to the host — audited by
+    ``pool_pulls`` / ``pool_pushes``.
   * :meth:`Session.poll_cq` / :meth:`Completion.result` are the receive
     side.  ``result()`` rings the doorbell on demand, so single-request
     control-path code stays one line.
@@ -350,10 +355,10 @@ class Session:
         population through this tenant's :attr:`view`.
 
         Do NOT hold the returned array across a doorbell: every wave
-        rebinds the endpoint's pool to the engine's output, so a stale
-        reference reads/writes an orphaned copy.  Re-fetch ``.pool``
-        (or use :meth:`write_region`/:meth:`read_region`) after each
-        doorbell."""
+        rebinds the endpoint's pool to the engine's output (on the
+        device), so a stale reference reads/writes an orphaned copy.
+        Re-fetch ``.pool`` (or use :meth:`write_region`/
+        :meth:`read_region`) after each doorbell."""
         return self.endpoint.host_mem()
 
     def write_region(self, region: str, data: Sequence[int], *,
@@ -363,9 +368,11 @@ class Session:
 
     def read_region(self, region: str, *, device: int = 0, offset: int = 0,
                     count: Optional[int] = None) -> np.ndarray:
-        return memory.read_region(self.endpoint._host_view(), self.view,
-                                  device, region, offset=offset,
-                                  count=count)
+        """Read ``count`` words of a region (default: to its end).  Only
+        those words move: a device-resident pool is sliced where it
+        lives, after every wave launched before the read."""
+        start, count = memory.region_span(self.view, region, offset, count)
+        return vm.pool_words(self.endpoint.mem, device, start, count)
 
     # -- data path ------------------------------------------------------
 
@@ -538,6 +545,12 @@ class TiaraEndpoint:
         self.cross_device_words = 0      # words served home != accessor
         self.rehome_count = 0
         self.rehomed_words = 0
+        # pool residency audit: whole-pool copies to the host (a
+        # control-path access, a blocking or mesh launch) and whole-pool
+        # uploads at a doorbell.  A split-phase data path leaves both
+        # unchanged after its first wave
+        self.pool_pulls = 0
+        self.pool_pushes = 0
 
     @classmethod
     def for_tenants(cls, named: Sequence[Tuple[str, RegionTable]], *,
@@ -591,21 +604,25 @@ class TiaraEndpoint:
         return self._sessions[tenant]
 
     def _host_view(self) -> np.ndarray:
-        """Host-side (possibly read-only) view of the pool.  While waves
-        are in flight the pool is a device future; viewing it blocks
-        until the last launched wave lands — a read must observe every
-        launched wave, in-flight or not."""
+        """Host-side (possibly read-only) view of the pool.  After a
+        split-phase doorbell the pool lives on the device (a future
+        while waves are in flight); viewing it blocks until the last
+        launched wave lands — a read must observe every launched wave,
+        in-flight or not — and copies the whole pool to the host once
+        (``pool_pulls``).  The next doorbell uploads it once
+        (``pool_pushes``)."""
         if not isinstance(self.mem, np.ndarray):
             self.mem = np.asarray(self.mem)
+            self.pool_pulls += 1
         return self.mem
 
     def host_mem(self) -> np.ndarray:
         """The pool, guaranteed host-writable for control-path access.
 
-        After a doorbell the pool may be a read-only view of the last
-        launch's device buffer (or, split-phase, a device future not yet
-        landed); the block + copy happen lazily here, so the data path
-        never pays for them."""
+        Retiring a wave leaves the pool on the device; a blocking
+        doorbell leaves a read-only host view of the launch's output.
+        The pull + copy happen lazily here, so the data path never pays
+        for them."""
         mem = self._host_view()
         if not mem.flags.writeable:
             self.mem = mem = mem.copy()
@@ -828,9 +845,12 @@ class TiaraEndpoint:
         pipeline against the in-flight wave (XLA chains the data
         dependency); completions retire later via ``poll_cq`` /
         ``wait_any`` / ``wait_all`` / ``Completion.wait()``, always in
-        wave order so per-session FIFO holds.  Modes that cannot defer
-        (sharded placement, "interp") still compute eagerly but retire
-        on the same split-phase path.
+        wave order so per-session FIFO holds.  Retiring brings back
+        each post's result, not the pool: the pool stays on the device
+        from wave to wave until a control-path access (``host_mem``,
+        ``write_region``, ``Session.pool``) pulls it.  Modes that
+        cannot defer (sharded placement, "interp") still compute
+        eagerly but retire on the same split-phase path.
 
         ``mode`` picks the wave engine: the mixed-dispatch set
         ("auto"/"mixed"/"segmented"/"serial") for any wave, "batched"/
@@ -914,6 +934,11 @@ class TiaraEndpoint:
         params = [list(c.params) for c in wave]
         homes = [c.home for c in wave]
         reg = self.registry
+        if placement != "single" and self.n_devices > 1:
+            # the mesh engine takes (and returns) the pool as a host
+            # array: pull a device-resident pool here, where it counts
+            self._host_view()
+        pushed = isinstance(self.mem, np.ndarray)
         block = wait  # split-phase doorbells defer result retirement
         failed = set(self.failed_devices) or None
         attempt = 0
@@ -979,6 +1004,10 @@ class TiaraEndpoint:
                     c.session._sq.append(c)
                 self._outstanding += len(wave)
                 raise
+        self.pool_pushes += pushed
+        # a blocking or mesh launch hands the pool back as a host array;
+        # a split-phase launch leaves it on the device
+        self.pool_pulls += isinstance(res.mem, np.ndarray)
         self.mem = res.mem
         handle = WaveHandle(self, self._wave_seq, wave, res)
         self._wave_seq += 1
@@ -1017,9 +1046,10 @@ class TiaraEndpoint:
     # -- completion retirement (the receive side) -------------------------
 
     def _retire(self, handle: WaveHandle) -> None:
-        """Deliver one wave's CQEs: materialize the (possibly deferred)
-        engine result, fill the completion handles, and append them to
-        their sessions' CQs in global arrival order.  Only
+        """Deliver one wave's CQEs: bring the (possibly deferred) engine
+        result's per-lane fields to the host — the pool stays where it
+        is — fill the completion handles, and append them to their
+        sessions' CQs in global arrival order.  Only
         :meth:`_retire_through` / :meth:`_retire_ready` call this, and
         only in wave order."""
         if self._poison_left > 0:
@@ -1030,10 +1060,6 @@ class TiaraEndpoint:
             raise faults.InjectedEngineError(
                 "injected materialization failure")
         res = vm.materialize_result(handle._res)
-        if self.mem is handle._res.mem:
-            # the pool still points at this wave's output: keep the
-            # materialized host view so later reads don't re-block
-            self.mem = res.mem
         # drop the result: a user-held Completion must not pin a whole
         # pool snapshot (the per-request fields are copied out below)
         handle._res = None

@@ -335,15 +335,23 @@ def write_region(mem: np.ndarray, table: RegionTable, device: int,
     mem[device, r.base + offset: r.base + offset + data.size] = data
 
 
-def read_region(mem: np.ndarray, table: RegionTable, device: int,
-                region: str, offset: int = 0,
-                count: Optional[int] = None) -> np.ndarray:
+def region_span(table: RegionTable, region: str, offset: int = 0,
+                count: Optional[int] = None) -> Tuple[int, int]:
+    """The pool words ``(start, count)`` of a read of ``count`` words
+    (default: to the region's end) at ``offset`` into ``region``."""
     r = table[region]
     if count is None:
         count = r.size - offset
-    if offset + count > r.size:
+    if offset < 0 or count < 0 or offset + count > r.size:
         raise ValueError("read exceeds region")
-    return np.asarray(mem[device, r.base + offset: r.base + offset + count])
+    return r.base + offset, count
+
+
+def read_region(mem: np.ndarray, table: RegionTable, device: int,
+                region: str, offset: int = 0,
+                count: Optional[int] = None) -> np.ndarray:
+    start, count = region_span(table, region, offset, count)
+    return np.asarray(mem[device, start: start + count])
 
 
 def bytes_to_words(n_bytes: int) -> int:

@@ -74,6 +74,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import weakref
 from typing import Dict, NamedTuple, Optional, Sequence, Set, Tuple, Union
 
@@ -1526,9 +1527,10 @@ def run_batched_fn(fn, mem: np.ndarray, p: np.ndarray, h: np.ndarray,
     With ``block=False`` the result fields are left as device arrays —
     XLA's async dispatch keeps computing while the caller goes on
     posting more work (the endpoint's split-phase doorbell); call
-    :func:`materialize_result` to retire them to numpy.  The launch
-    itself (tracing, validation, cache lookup) still happens eagerly,
-    so malformed waves raise here either way."""
+    :func:`materialize_result` to retire the per-lane fields to numpy
+    (the pool stays on the device).  The launch itself (tracing,
+    validation, cache lookup) still happens eagerly, so malformed
+    waves raise here either way."""
     n_dev = int(mem.shape[0])
     with x64():
         out = fn(jnp.asarray(mem, jnp.int64), jnp.asarray(p),
@@ -1541,26 +1543,55 @@ def run_batched_fn(fn, mem: np.ndarray, p: np.ndarray, h: np.ndarray,
 
 
 def materialize_result(res: "BatchedInvokeResult") -> "BatchedInvokeResult":
-    """Retire a (possibly deferred) batched result to host numpy arrays.
-    Blocks until the launch that produced it completes; a no-op on an
-    already-materialized result."""
+    """Retire a (possibly deferred) batched result: the per-lane fields
+    (``ret``, ``status``, ``steps``, ``regs``, ``fault``) come to host
+    numpy, and ``mem`` is left where it is — a device array, or a
+    future still computing.  Blocks until the launch that produced the
+    per-lane fields completes; a no-op on an already-materialized
+    result.  The pool crosses to the host only when a caller asks for
+    it (the endpoint's control path)."""
+    ret, status, steps, regs, fault = jax.device_get(
+        (res.ret, res.status, res.steps, res.regs, res.fault))
     return BatchedInvokeResult(
-        mem=np.asarray(res.mem), ret=np.asarray(res.ret),
-        status=np.asarray(res.status), steps=np.asarray(res.steps),
-        regs=np.asarray(res.regs), fault=np.asarray(res.fault))
+        mem=res.mem, ret=np.asarray(ret), status=np.asarray(status),
+        steps=np.asarray(steps), regs=np.asarray(regs),
+        fault=np.asarray(fault))
 
 
 def result_ready(res: "BatchedInvokeResult") -> bool:
     """Non-blocking readiness probe of a deferred batched result: True
-    once every field's device computation has landed (numpy fields are
-    trivially ready; jax arrays without ``is_ready`` report ready and
-    the subsequent materialization simply blocks)."""
-    for f in (res.mem, res.ret, res.status, res.steps, res.regs,
-              res.fault):
+    once every per-lane field's device computation has landed (numpy
+    fields are trivially ready; jax arrays without ``is_ready`` report
+    ready and the subsequent materialization simply blocks).  The pool
+    is not probed: retirement does not bring it back."""
+    for f in (res.ret, res.status, res.steps, res.regs, res.fault):
         probe = getattr(f, "is_ready", None)
         if probe is not None and not probe():
             return False
     return True
+
+
+@functools.partial(jax.jit, static_argnames="count")
+def _pool_slice(mem, row, start, *, count):
+    return lax.dynamic_slice(mem, (row, start), (1, count))[0]
+
+
+def pool_words(mem, device: int, start: int, count: int) -> np.ndarray:
+    """Words ``[start, start + count)`` of pool row ``device`` as host
+    int64.  A host pool is sliced in place (a view); a device pool — or
+    a future still computing, which orders the read after the wave that
+    produces it — is sliced where it lives, so only ``count`` words
+    cross to the host.  One compile per ``count``: the offsets are
+    traced."""
+    if not 0 <= device < mem.shape[0]:
+        # a traced row index would be clamped, not refused
+        raise IndexError(f"pool row {device} out of range "
+                         f"[0, {mem.shape[0]})")
+    if isinstance(mem, np.ndarray):
+        return mem[device, start:start + count]
+    with x64():
+        return np.asarray(_pool_slice(mem, np.int64(device),
+                                      np.int64(start), count=int(count)))
 
 
 def _wrap_param(v) -> np.int64:

@@ -87,7 +87,7 @@ def _connect(n_tenants=3, **kwargs):
 def _oracle_replay(ep, completions):
     """Replay posts one at a time on pyvm in global arrival order."""
     vops = ep.registry.store_ops()
-    seq = ep.mem.copy()
+    seq = ep._host_view().copy()
     expect = {}
     for c in sorted(completions, key=lambda c: c.seq):
         r = pyvm.run(vops[c.op_id], ep.regions, seq, list(c.params),

@@ -421,7 +421,7 @@ def _overload_run(seed, *, n_tenants=4, n_posts=64, slow=False):
     assert max(bound_log) <= cfg.max_inflight_waves
     # oracle parity for everything that executed, in launch order
     mem, expect = _oracle_replay(ep, mem0, order)
-    assert np.array_equal(ep.mem, mem)
+    assert np.array_equal(ep._host_view(), mem)
     for c in order:
         assert (c.ret, c.status, c.steps) == expect[c.seq], c
     st = loop.stats
